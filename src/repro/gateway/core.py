@@ -179,6 +179,8 @@ class AsyncGateway:
             qt: asyncio.Event() for qt in self.queues}
         self._tasks: List[asyncio.Task] = []
         self._running = False
+        # the first exception that killed a dispatcher: stop() re-raises it
+        self._failure: Optional[Exception] = None
         self._t0 = time.monotonic()
         if len(self._apps) == 1 and "" in self._apps:
             st = self._apps[""]
@@ -226,11 +228,14 @@ class AsyncGateway:
                                     name=f"dispatch:{qt}"))
 
     async def stop(self) -> None:
+        """Stop the dispatchers; re-raises the fault that killed one."""
         self._running = False
         for t in self._tasks:
             t.cancel()
         await asyncio.gather(*self._tasks, return_exceptions=True)
         self._tasks.clear()
+        if self._failure is not None:
+            raise self._failure
 
     # -- intake --------------------------------------------------------
     async def submit(self, app: str) -> GatewayRequest:
@@ -240,6 +245,9 @@ class AsyncGateway:
         if st is None:
             raise KeyError(f"unknown app {app!r} "
                            f"(gateway serves {sorted(self._apps)})")
+        if self._failure is not None:
+            raise RuntimeError("gateway dispatcher failed") \
+                from self._failure
         now = self.now()
         entry = st.graph.entry
         qt = qualify(app, entry)
@@ -279,7 +287,11 @@ class AsyncGateway:
         while self._running:
             now = self.now()
             self._drop_scan(qt, now)
-            self._try_launch(qt, now)
+            try:
+                self._try_launch(qt, now)
+            except Exception as e:
+                self._fail(e)
+                raise
             q = self.queues[qt]
             delay = None
             if q:
@@ -296,6 +308,24 @@ class AsyncGateway:
             except asyncio.TimeoutError:
                 pass
             ev.clear()
+
+    def _fail(self, exc: Exception) -> None:
+        """A backend fault: remember it for ``stop()``, and resolve every
+        in-flight request with status ``error`` so no caller waits on a
+        dispatcher that is gone."""
+        if self._failure is None:
+            self._failure = exc
+        now = self.now()
+        for gr in self._roots.values():
+            gr.outcome = {"event": "done", "root_id": gr.root_id,
+                          "app": gr.app, "status": "error",
+                          "error": f"{type(exc).__name__}: {exc}",
+                          "latency_ms": (now - gr.arrival_s) * 1e3,
+                          "deadline_met": False}
+            gr.finished_s = now
+            gr.events.put_nowait(gr.outcome)
+            gr.done.set()
+        self._roots.clear()
 
     def _drop_scan(self, qt: str, now: float) -> None:
         q = self.queues[qt]

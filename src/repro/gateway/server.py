@@ -30,9 +30,10 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple, Union
 from urllib.parse import parse_qs, urlsplit
 
+from repro.core.taskgraph import TaskGraph
 from repro.gateway.core import AdmissionRejected, AsyncGateway
 from repro.obs import Instrumentation, Tracer
 
@@ -245,8 +246,8 @@ class GatewayHTTPServer:
 
 
 # ----------------------------------------------------------------------
-def build_demo_gateway(apps: Sequence[str] = ("social_media",
-                                              "traffic_analysis"), *,
+def build_demo_gateway(apps: Sequence[Union[str, TaskGraph]] = (
+                           "social_media", "traffic_analysis"), *,
                        plan_rps: float = 30.0, s_avail: int = 64,
                        time_scale: float = 1.0, seed: int = 0,
                        sample_every: int = 1,
@@ -256,9 +257,11 @@ def build_demo_gateway(apps: Sequence[str] = ("social_media",
                        ) -> Tuple[AsyncGateway, Instrumentation]:
     """Plan each app with the MILP and wrap the deployment in an
     instrumented gateway — the shared entry point for the CLI, the smoke
-    job, the benchmarks, and the tests.  The instrumentation carries the
-    full observability plane: tracer, SLO error-budget ledgers with the
-    SRE burn-rate rules, and the control-plane flight recorder."""
+    jobs, the benchmarks, and the tests.  An app is a name from
+    ``core.apps`` or a :class:`TaskGraph`, served under its ``name``.
+    The instrumentation carries the full observability plane: tracer, SLO
+    error-budget ledgers with the SRE burn-rate rules, and the
+    control-plane flight recorder."""
     from repro.core.apps import get_app
     from repro.core.milp import Planner
     from repro.core.profiler import Profiler
@@ -267,8 +270,9 @@ def build_demo_gateway(apps: Sequence[str] = ("social_media",
     hooks = Instrumentation(tracer=Tracer(sample_every=sample_every),
                             slo=SloPlane(), audit=AuditLog())
     planned = {}
-    for name in apps:
-        g = get_app(name)
+    for app in apps:
+        g = app if isinstance(app, TaskGraph) else get_app(app)
+        name = g.name
         prof = Profiler(g)
         cfg = Planner(g, prof, s_avail=s_avail, max_tuples_per_task=32,
                       bb_nodes=4, bb_time_s=1.0).plan(plan_rps)
@@ -297,6 +301,9 @@ async def _amain(args: argparse.Namespace) -> None:
 
 
 def main() -> None:
+    from repro.runtime.backend import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description="serve planned apps over HTTP")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8780)

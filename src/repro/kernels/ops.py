@@ -1,12 +1,11 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels TARGET TPU) — on a real TPU backend the flag resolves to False and
-the kernels lower to Mosaic.  Set ``REPRO_KERNEL_INTERPRET=0/1`` to force.
+The kernels target TPU and lower to Mosaic there.  On the CPU backend
+(tests) they run in Pallas interpret mode; any other backend is an error,
+so a run on the wrong device fails instead of silently interpreting.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional, Tuple
 
@@ -21,10 +20,13 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
 def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels target TPU; backend {backend!r} "
+                       "has neither Mosaic nor the CPU interpreter path")
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_kv"))

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _qmm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
                 n_k: int):
@@ -32,14 +30,14 @@ def _qmm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # int8 operands straight into the MXU; Mosaic refuses an i32 x i32 dot
     acc_ref[...] += jax.lax.dot_general(
-        x_ref[...].astype(jnp.int32), w_ref[...].astype(jnp.int32),
+        x_ref[...], w_ref[...],
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
 
     @pl.when(kk == n_k - 1)
     def _epilogue():
-        out = (acc_ref[...].astype(jnp.float32)
-               * xs_ref[...][:, None] * ws_ref[...][None, :])
+        out = acc_ref[...].astype(jnp.float32) * xs_ref[...] * ws_ref[...]
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -74,13 +72,16 @@ def quant_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bm,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            # scales as [M,1] / [1,N] columns and rows: 1-D blocks do not
+            # match XLA's tiled layout of a long f32 vector
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x_q, w_q, x_scale.astype(jnp.float32), w_scale.astype(jnp.float32))
+    )(x_q, w_q, x_scale.astype(jnp.float32).reshape(M, 1),
+      w_scale.astype(jnp.float32).reshape(1, N))

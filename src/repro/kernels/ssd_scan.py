@@ -24,11 +24,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
-
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref,
+def _ssd_kernel(x_ref, dtc_ref, dtr_ref, a_ref, b_ref, c_ref, s0_ref,
                 y_ref, sf_ref, state_ref, *, q: int, n_chunks: int):
+    h = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -36,23 +35,33 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref,
         state_ref[...] = s0_ref[0, 0]
 
     x = x_ref[0, 0].astype(jnp.float32)           # [q, hd]
-    dt = dt_ref[0, 0].astype(jnp.float32)         # [q]
-    A = a_ref[0]                                  # scalar decay rate (<0)
+    A = a_ref[h]                                  # scalar decay rate (<0)
+    # dt arrives twice, as a column [q,1] and a row [1,q], so that both
+    # orientations of the chunk's cumulative decay come from masked
+    # reductions instead of a cumsum or an in-kernel transpose
+    dt_c = dtc_ref[0, 0].astype(jnp.float32)      # [q, 1]
+    dt_r = dtr_ref[0, 0, 0].astype(jnp.float32)   # [1, q]
     Bm = b_ref[0].astype(jnp.float32)             # [q, ds]
     Cm = c_ref[0].astype(jnp.float32)             # [q, ds]
 
-    dA = dt * A                                   # [q] (<= 0)
-    cs = jnp.cumsum(dA)                           # [q]
+    iota_i = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    iota_j = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    causal = iota_i >= iota_j
+    dA_c = dt_c * A                               # [q, 1] (<= 0)
+    dA_r = dt_r * A                               # [1, q]
+    cs_c = jnp.sum(jnp.where(causal, jnp.broadcast_to(dA_r, (q, q)), 0.0),
+                   axis=1, keepdims=True)         # [q, 1] inclusive cumsum
+    cs_r = jnp.sum(jnp.where(iota_i <= iota_j,
+                             jnp.broadcast_to(dA_c, (q, q)), 0.0),
+                   axis=0, keepdims=True)         # [1, q]
+    total = jnp.sum(dA_r, axis=1, keepdims=True)  # [1, 1] = cs[q-1]
 
     # intra-chunk: L[i,j] = exp(cs_i - cs_j) for i >= j  (mask the
     # exponent, not the output — masked diffs are positive and overflow)
-    diff = cs[:, None] - cs[None, :]
-    iota_i = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    iota_j = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    L = jnp.exp(jnp.where(iota_i >= iota_j, diff, -1e30))
+    L = jnp.exp(jnp.where(causal, cs_c - cs_r, -1e30))
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [q,q]
-    xdt = x * dt[:, None]
+    xdt = x * dt_c
     y = jax.lax.dot_general(L * scores, xdt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)       # [q,hd]
 
@@ -60,15 +69,15 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref,
     state = state_ref[...]                        # [hd, ds]
     y_off = jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)   # [q,hd]
-    y = y + y_off * jnp.exp(cs)[:, None]
+    y = y + y_off * jnp.exp(cs_c)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: state' = state * exp(cs_last) + Σ_j decay_j dt_j x_j ⊗ B_j
-    decay_states = jnp.exp(cs[q - 1] - cs)        # [q]
-    wx = x * (decay_states * dt)[:, None]         # [q, hd]
+    decay_states = jnp.exp(total - cs_c)          # [q, 1]
+    wx = x * (decay_states * dt_c)                # [q, hd]
     new_contrib = jax.lax.dot_general(wx, Bm, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
-    state_ref[...] = state * jnp.exp(cs[q - 1]) + new_contrib
+    state_ref[...] = state * jnp.exp(total) + new_contrib
 
     @pl.when(ci == n_chunks - 1)
     def _emit_state():
@@ -94,7 +103,8 @@ def ssd_scan_pallas(
     n_chunks = S // q
 
     xr = x.transpose(0, 2, 1, 3)                  # [B, nh, S, hd]
-    dtr = dt.transpose(0, 2, 1)                   # [B, nh, S]
+    dtc = dt.transpose(0, 2, 1)[..., None]        # [B, nh, S, 1]
+    dtr = dt.transpose(0, 2, 1).reshape(B, nh, n_chunks, 1, q)
     s0 = (init_state if init_state is not None
           else jnp.zeros((B, nh, hd, ds), jnp.float32))
 
@@ -104,8 +114,9 @@ def ssd_scan_pallas(
         grid=(B, nh, n_chunks),
         in_specs=[
             pl.BlockSpec((1, 1, q, hd), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, q), lambda b, h, c: (b, h, c)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, q, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, 1, q), lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, q, ds), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, q, ds), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, 1, hd, ds), lambda b, h, c: (b, h, 0, 0)),
@@ -119,8 +130,8 @@ def ssd_scan_pallas(
             jax.ShapeDtypeStruct((B, nh, hd, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xr, dtr, A.astype(jnp.float32), Bm, Cm, s0)
+    )(xr, dtc, dtr, A.astype(jnp.float32), Bm, Cm, s0)
     return y.transpose(0, 2, 1, 3), final

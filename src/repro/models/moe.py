@@ -33,18 +33,6 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-# optimization_barrier only grew a differentiation rule in later jax
-# releases; the barrier is value-identity, so pass tangents through
-@jax.custom_jvp
-def _opt_barrier(x):
-    return jax.lax.optimization_barrier(x)
-
-
-@_opt_barrier.defjvp
-def _opt_barrier_jvp(primals, tangents):
-    return _opt_barrier(primals[0]), tangents[0]
-
-
 def init_moe(key, arch: ArchConfig, n_layers: int, dtype) -> Params:
     m = arch.moe
     d, fe, E = arch.d_model, m.d_ff_expert, m.num_experts
@@ -188,7 +176,7 @@ def _dispatch_grouped(x, p, arch, policy):
     xg = policy.pin(xg, "batch", None, None)
     # barrier: keeps the (bf16) gather of seq-sharded tokens from being
     # convert-hoisted into fp32 by the fusing of the routing matmul
-    xg = _opt_barrier(xg)
+    xg = jax.lax.optimization_barrier(xg)
 
     gate, idx = _route(xg, p, m)                      # [G, Sg, K]
     cap = _round_up(max(int(m.capacity_factor * K * Sg / E), 1), 8)

@@ -9,15 +9,18 @@ Two implementations:
   from the legacy ``Simulator`` (p95 latency × lognormal jitter; the tail
   models stragglers).
 * :class:`EngineBackend` — drives real :class:`repro.serving.engine.Engine`
-  instances (reduced archs, CPU) and uses the measured wall-clock
-  generation time as the service time, so the same control loop and
-  scenarios exercise the actual jit'd datapath.
+  instances (reduced float32 archs on the CPU, or published widths in
+  bf16 on a TPU) and uses the measured wall-clock generation time as the
+  service time, so the same control loop and scenarios exercise the
+  actual jit'd datapath.
 """
 from __future__ import annotations
 
+import os
 import time
 import zlib
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import (Any, Dict, List, Mapping, Optional, Protocol, Sequence,
                     TYPE_CHECKING, runtime_checkable)
 
@@ -78,16 +81,35 @@ class SimBackend:
         pass
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and nothing
+    is changed here.  Otherwise the cache goes to ``<checkout>/.jax_cache``:
+    a fixed path, so every run from this checkout finds what earlier runs
+    compiled.  Call it at the start of a program, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 # ---------------------------------------------------------------------------
 @dataclass
 class EngineBackend:
-    """Serve batches on real ``serving.Engine`` instances (CPU, reduced
-    archs — the small-config parity path).
+    """Serve batches on real ``serving.Engine`` instances.
 
-    One engine is built per distinct model arch on first use; its jit
-    compile is excluded from service times by a warmup generate.  Service
-    time is the measured wall-clock of the batched greedy decode, scaled
-    by ``time_scale`` (sim-seconds per wall-second).
+    Each arch is served as its ``reduced()`` float32 variant (the
+    small-config CPU parity path), or, with ``full_width``, as published
+    in bf16 (the chip path).  ``bind`` builds one engine per distinct arch
+    the plan deploys: parameters are drawn inside one jit, and a warm-up
+    generate at ``prompt_len`` compiles every batch size from 1 to
+    ``max_batch``, so no compile lands inside a timed ``service_s``.
+    Service time is the measured wall-clock of the batched greedy decode,
+    scaled by ``time_scale`` (sim-seconds per wall-second).
 
     ``pool_time_scale`` maps a ClusterSpec pool name to ITS scale so a
     heterogeneous CPU parity run reflects relative device speeds (e.g.
@@ -101,6 +123,7 @@ class EngineBackend:
     max_new: int = 4
     time_scale: float = 1.0
     pool_time_scale: Optional[Mapping[str, float]] = None
+    full_width: bool = False
     _engines: Dict[str, Any] = field(default_factory=dict, repr=False)
     # one graph per bound app ("" = single-app); engines are shared
     # across apps by arch — co-located apps reuse the same jit'd engine
@@ -108,9 +131,12 @@ class EngineBackend:
 
     def bind(self, graph, config, app=""):
         self._graphs[app] = graph
+        for tup, _ in config.instances():
+            self.engine_for(graph.tasks[tup.task].variant(tup.variant).arch)
 
     # ------------------------------------------------------------------
-    def _engine_for(self, arch_name: str):
+    def engine_for(self, arch_name: str):
+        """The warmed engine serving ``arch_name`` (built on first use)."""
         eng = self._engines.get(arch_name)
         if eng is None:
             import jax
@@ -120,18 +146,23 @@ class EngineBackend:
             from repro.serving.engine import Engine, EngineConfig
             from repro.sharding.policy import ShardingPolicy
 
-            arch = ARCHS[arch_name].reduced()
+            if self.full_width:
+                arch, dtype = ARCHS[arch_name], jnp.bfloat16
+            else:
+                arch, dtype = ARCHS[arch_name].reduced(), jnp.float32
             model = Model(arch, ShardingPolicy(mesh=None),
-                          param_dtype=jnp.float32)
+                          param_dtype=dtype)
             # stable per-arch seed (str hash is salted per process)
             seed = zlib.crc32(arch_name.encode()) & 0x7FFFFFFF
-            params = model.init(jax.random.key(seed))
+            # one program: the float32 draws are its temporaries, which XLA
+            # frees as it casts them, and the init costs one dispatch
+            params = jax.jit(model.init)(jax.random.key(seed))
             eng = Engine(model, params,
                          EngineConfig(max_batch=self.max_batch,
                                       max_seq=self.max_seq))
-            # warmup: trigger the prefill/decode jit outside timed serving
-            warm = np.zeros((1, self.prompt_len), np.int32)
-            eng.generate(warm, max_new=2)
+            for b in range(1, self.max_batch + 1):
+                eng.generate(np.zeros((b, self.prompt_len), np.int32),
+                             max_new=2)
             self._engines[arch_name] = eng
         return eng
 
@@ -145,7 +176,7 @@ class EngineBackend:
         graph = self._graphs[getattr(server, "app", "")]
         task = graph.tasks[server.tup.task]
         arch_name = task.variant(server.tup.variant).arch
-        eng = self._engine_for(arch_name)
+        eng = self.engine_for(arch_name)
         vocab = eng.model.arch.vocab_size
         b = min(max(len(batch), 1), eng.cfg.max_batch)
         prompts = np.asarray(

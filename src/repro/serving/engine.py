@@ -28,7 +28,11 @@ class EngineConfig:
 
 
 class Engine:
-    """Continuous-batching serving engine for one model instance."""
+    """Continuous-batching serving engine for one model instance.
+
+    ``prefill(params, tokens)`` and ``decode(params, cache, cache_len,
+    tokens)`` are the jitted programs ``generate`` runs; each returns
+    ``(logits, cache)``."""
 
     def __init__(self, model: Model, params, cfg: EngineConfig):
         self.model = model
@@ -46,11 +50,11 @@ class Engine:
             from jax.sharding import NamedSharding
             pspec = jax.tree.map(lambda s: NamedSharding(mesh, s),
                                  model.param_specs())
-            self._prefill = jax.jit(prefill, in_shardings=(pspec, None))
-            self._decode = jax.jit(decode, donate_argnums=(1,))
+            self.prefill = jax.jit(prefill, in_shardings=(pspec, None))
+            self.decode = jax.jit(decode, donate_argnums=(1,))
         else:
-            self._prefill = jax.jit(prefill)
-            self._decode = jax.jit(decode, donate_argnums=(1,))
+            self.prefill = jax.jit(prefill)
+            self.decode = jax.jit(decode, donate_argnums=(1,))
 
         self.cache = None
         self.cache_len = 0
@@ -63,7 +67,7 @@ class Engine:
         same length — the batcher pads).  Returns [B, max_new]."""
         B, S = prompts.shape
         assert B <= self.cfg.max_batch and S < self.cfg.max_seq
-        logits, cache = self._prefill(self.params, jnp.asarray(prompts))
+        logits, cache = self.prefill(self.params, jnp.asarray(prompts))
         out = np.zeros((B, max_new), np.int32)
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         done = np.zeros((B,), bool)
@@ -75,7 +79,7 @@ class Engine:
                     break
             if i == max_new - 1:
                 break
-            logits, cache = self._decode(self.params, cache,
+            logits, cache = self.decode(self.params, cache,
                                          jnp.int32(S + i), tok)
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         return out

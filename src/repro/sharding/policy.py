@@ -69,8 +69,8 @@ class ShardingPolicy:
                 continue
             ax = tuple(a for a in ax if a not in used)
             used.update(ax)
-            # bare name for a single axis: older jax PartitionSpec
-            # equality does not canonicalize ('x',) to 'x'
+            # bare name for a single axis, so a spec compares equal to
+            # one written by hand as P('x') rather than P(('x',))
             out.append(None if not ax else ax[0] if len(ax) == 1 else ax)
         return P(*out)
 

@@ -10,6 +10,18 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
 
+# pytest-xdist workers share the machine's cores.  A multithreaded OpenBLAS
+# in every worker spins against the others and slows the planner's small
+# LP solves ~10x, past the wall-time budget (bb_time_s) that some fixtures
+# plan under.  numpy is already loaded here, so an environment variable
+# would come too late: cap the loaded BLAS at one thread per worker.
+try:
+    from threadpoolctl import threadpool_limits
+except ImportError:  # pragma: no cover - only the thread cap is lost
+    pass
+else:
+    threadpool_limits(limits=1, user_api="blas")
+
 
 @pytest.fixture(scope="session")
 def null_policy():

@@ -226,6 +226,36 @@ def test_gateway_unknown_app_fails_loud(planned_apps):
     asyncio.run(drive())
 
 
+def test_gateway_backend_fault_reaches_caller(planned_apps):
+    """A service_s that raises resolves the waiting request with status
+    'error', refuses later submits, and stop() re-raises the fault —
+    nothing waits forever on a dead dispatcher."""
+    class Broken:
+        def bind(self, graph, config, app=""):
+            pass
+
+        def service_s(self, server, batch, now_s, rng):
+            raise RuntimeError("device lost")
+
+        def on_capacity_change(self, servers):
+            pass
+
+    async def drive():
+        gw = AsyncGateway(planned_apps, Broken(), seed=0, time_scale=0.2)
+        await gw.start()
+        gr = await gw.submit("social_media")
+        await asyncio.wait_for(gr.done.wait(), timeout=10.0)
+        with pytest.raises(RuntimeError, match="dispatcher failed"):
+            await gw.submit("social_media")
+        with pytest.raises(RuntimeError, match="device lost"):
+            await gw.stop()
+        return gr
+
+    gr = asyncio.run(drive())
+    assert gr.outcome["status"] == "error"
+    assert "device lost" in gr.outcome["error"]
+
+
 def test_http_server_smoke(planned_apps):
     """Boot the stdlib HTTP server on an ephemeral port and exercise
     every route over real sockets: healthz, submit (unary + streamed
