@@ -30,6 +30,10 @@ door policies stack in front of it (DESIGN.md §17):
 * **Retry-on-drop** — with ``retry_drops=True`` a queued hop that the
   early-drop scan sheds (deadline still feasible) is resubmitted ONCE
   at the back of its queue instead of failing the root request.
+
+The loop's own time is accounted by state (:class:`_LoopStates`), and,
+with an instrumentation registry, published at scrape time together with
+the backend's engine phases and compile counts (``_publish``).
 """
 from __future__ import annotations
 
@@ -48,6 +52,7 @@ from repro.core.dispatch import (QueuedRequest, batch_ready, early_drop,
 from repro.core.frontend import Frontend
 from repro.core.milp import PlanConfig
 from repro.core.taskgraph import TaskGraph, qualify, split_qualified
+from repro.obs.tracing import ProfilerInterval
 from repro.runtime.backend import ExecutionBackend, SimBackend
 from repro.runtime.cluster import _AppState
 from repro.runtime.metrics import Server
@@ -57,6 +62,67 @@ __all__ = ["AdmissionRejected", "AsyncGateway", "GatewayRequest"]
 # floor on dispatcher timer waits: below this asyncio timer resolution
 # costs more than the wait buys
 _MIN_WAIT_S = 0.001
+
+# the loop's states, in their order of precedence
+LOOP_STATES = ("generate", "replay", "batch_wait", "empty")
+_LOOP_SPANS = tuple(f"gw.{s}" for s in LOOP_STATES)
+
+
+class _LoopStates:
+    """Which state the gateway's event loop is in, and the wall seconds
+    each state has held while the gateway ran.  In order of precedence:
+    ``generate`` while the loop is inside ``backend.service_s``;
+    ``replay`` while a ``_serve`` sleep is pending (a finished batch's
+    results are held back); ``batch_wait`` while a task queue holds a
+    request; ``empty`` otherwise.  The gateway moves the three running
+    counts at its own events and calls :meth:`update`, which reads the
+    clock only where the state changes, and opens one ``gw.<state>``
+    profiler annotation per state interval."""
+
+    __slots__ = ("generating", "replays", "queued", "seconds", "state",
+                 "since", "_spans")
+
+    def __init__(self) -> None:
+        self.generating = False
+        self.replays = 0                 # pending _serve sleeps
+        self.queued = 0                  # requests in every task queue
+        self.seconds = [0.0] * len(LOOP_STATES)
+        self.state = len(LOOP_STATES) - 1
+        self.since: Optional[float] = None   # None while not running
+        self._spans = ProfilerInterval()
+
+    def _which(self) -> int:
+        return (0 if self.generating else 1 if self.replays
+                else 2 if self.queued else 3)
+
+    def update(self) -> None:
+        s = self._which()
+        if s == self.state:
+            return
+        if self.since is not None:
+            t = time.monotonic()
+            self.seconds[self.state] += t - self.since
+            self.since = t
+            self._spans.switch(_LOOP_SPANS[s])
+        self.state = s
+
+    def begin(self) -> None:
+        self.state = self._which()
+        self.since = time.monotonic()
+        self._spans.switch(_LOOP_SPANS[self.state])
+
+    def end(self) -> None:
+        if self.since is not None:
+            self.seconds[self.state] += time.monotonic() - self.since
+            self.since = None
+        self._spans.switch(None)
+
+    def totals(self) -> List[float]:
+        """Seconds per state, the current one counted up to now."""
+        out = list(self.seconds)
+        if self.since is not None:
+            out[self.state] += time.monotonic() - self.since
+        return out
 
 
 class AdmissionRejected(Exception):
@@ -182,12 +248,16 @@ class AsyncGateway:
         # the first exception that killed a dispatcher: stop() re-raises it
         self._failure: Optional[Exception] = None
         self._t0 = time.monotonic()
+        self._loop = _LoopStates()
         if len(self._apps) == 1 and "" in self._apps:
             st = self._apps[""]
             self.backend.bind(st.graph, st.config)
         else:
             for name, st in self._apps.items():
                 self.backend.bind(st.graph, st.config, app=name)
+        registry = getattr(hooks, "registry", None)
+        if registry is not None:
+            self._publish(registry)
 
     # -- clock ---------------------------------------------------------
     def now(self) -> float:
@@ -222,6 +292,15 @@ class AsyncGateway:
             return
         self._running = True
         self._t0 = time.monotonic()
+        # the profiler's host clock at gateway time 0, so that the
+        # tracer's spans lay over a profiler trace
+        anchor_ns = time.time_ns()
+        tracer = getattr(self.hooks, "tracer", None)
+        if tracer is not None:
+            tracer.anchor_ns = anchor_ns
+            tracer.wall_per_unit = self.time_scale
+        self._loop.queued = sum(len(q) for q in self.queues.values())
+        self._loop.begin()
         for qt in self.queues:
             self._tasks.append(
                 asyncio.create_task(self._dispatch_loop(qt),
@@ -234,6 +313,7 @@ class AsyncGateway:
             t.cancel()
         await asyncio.gather(*self._tasks, return_exceptions=True)
         self._tasks.clear()
+        self._loop.end()
         if self._failure is not None:
             raise self._failure
 
@@ -272,6 +352,8 @@ class AsyncGateway:
         self._roots[rid] = gr
         req = QueuedRequest(rid, rid, qt, now, gr.deadline_s)
         self.queues[qt].append(req)
+        self._loop.queued += 1
+        self._loop.update()
         if self.hooks is not None:
             self.hooks.on_arrival(app, entry, now, len(self.queues[qt]))
         self._wake[qt].set()
@@ -345,6 +427,9 @@ class AsyncGateway:
                 retry = self._drop(req, qt, rkey, now)
                 if retry is not None:
                     keep.append(retry)
+        if len(keep) != len(q):
+            self._loop.queued -= len(q) - len(keep)
+            self._loop.update()
         self.queues[qt] = keep
 
     def _try_launch(self, qt: str, now: float) -> None:
@@ -364,21 +449,41 @@ class AsyncGateway:
                 srv = min(idle, key=lambda s: s.tup.batch)
             batch = q[: srv.tup.batch]
             del q[: srv.tup.batch]
-            service = self.backend.service_s(srv, batch, now, self.rng)
+            loop = self._loop
+            loop.queued -= len(batch)
+            loop.generating = True
+            loop.update()
+            try:
+                service = self.backend.service_s(srv, batch, now, self.rng)
+            finally:
+                loop.generating = False
+                loop.update()
             srv.busy_until = now + service
             srv.served += len(batch)
             if self.hooks is not None:
-                self.hooks.on_dispatch(srv, batch, now, service, len(q))
+                # the batch is released by _serve, one sleep later
+                self.hooks.on_dispatch(srv, batch, now, service, len(q),
+                                       held=True)
             asyncio.get_running_loop().create_task(
-                self._serve(srv, qt, batch, service))
+                self._serve(srv, qt, batch, service, now))
 
     async def _serve(self, srv: Server, qt: str,
-                     batch: List[QueuedRequest], service: float) -> None:
-        await asyncio.sleep(service * self.time_scale)
-        now = self.now()
-        srv.busy_until = now
-        for req in batch:
-            self._complete_hop(req, srv, now)
+                     batch: List[QueuedRequest], service: float,
+                     launch: float) -> None:
+        loop = self._loop
+        loop.replays += 1
+        loop.update()
+        try:
+            await asyncio.sleep(service * self.time_scale)
+            now = self.now()
+            srv.busy_until = now
+            if self.hooks is not None:
+                self.hooks.on_release(srv, batch, launch, now, service)
+            for req in batch:
+                self._complete_hop(req, srv, now)
+        finally:
+            loop.replays -= 1
+            loop.update()
         self._wake[qt].set()
 
     def _complete_hop(self, req: QueuedRequest, srv: Server,
@@ -422,6 +527,7 @@ class AsyncGateway:
                                       now, req.deadline,
                                       req.path_done + (task,))
                 self.queues[qt2].append(child)
+            self._loop.queued += fan
             self._wake[qt2].set()
         if gr is not None:
             gr.outstanding -= 1
@@ -467,6 +573,51 @@ class AsyncGateway:
         return None
 
     # -- introspection -------------------------------------------------
+    def _publish(self, registry: Any) -> None:
+        """Expose the loop's state seconds, and the backend's engine
+        phases and compile counts where it keeps them, as counters that
+        a collector fills at scrape time (the cold path)."""
+        loop_s = registry.counter(
+            "jigsaw_gateway_loop_seconds_total",
+            "Gateway event-loop wall seconds by state: generate (inside "
+            "backend.service_s), replay (a finished batch held back), "
+            "batch_wait (a task queue holds a request), empty",
+            ("state",))
+        phases = getattr(self.backend, "engine_phases", None)
+        compiles = getattr(self.backend, "compile_totals", None)
+        if phases is not None:
+            gens = registry.counter(
+                "jigsaw_engine_generates_total",
+                "Engine.generate calls", ("arch",))
+            secs = registry.counter(
+                "jigsaw_engine_seconds_total",
+                "Engine.generate wall seconds by phase: host (dispatch of "
+                "prefill, decode and argmax), sync (blocked pulling each "
+                "token to the host)", ("arch", "phase"))
+        if compiles is not None:
+            n_c = registry.counter(
+                "jigsaw_compiles_total",
+                "XLA compiles in the process, persistent-cache loads "
+                "included")
+            s_c = registry.counter(
+                "jigsaw_compile_seconds_total",
+                "Seconds of the XLA compiles in jigsaw_compiles_total")
+
+        def collect() -> None:
+            for state, v in zip(LOOP_STATES, self._loop.totals()):
+                loop_s.set_total(v, state)
+            if phases is not None:
+                for arch, (g, host, sync) in phases().items():
+                    gens.set_total(g, arch)
+                    secs.set_total(host, arch, "host")
+                    secs.set_total(sync, arch, "sync")
+            if compiles is not None:
+                n, seconds = compiles()
+                n_c.set_total(n)
+                s_c.set_total(seconds)
+
+        registry.add_collector(collect)
+
     def stats(self) -> dict:
         return {
             "apps": sorted(self._apps),

@@ -198,12 +198,17 @@ class Instrumentation:
                               latency_ms=round(latency_ms, 3))
 
     def on_dispatch(self, server: Any, batch: Sequence[Any], now: float,
-                    service_s: float, queue_len: int) -> None:
+                    service_s: float, queue_len: int,
+                    held: bool = False) -> None:
         """Called at batch launch — service time is already known (the
         backend computed it), so queue/service/hop spans are recorded in
         one shot.  The scalars are captured NOW (the ladder mutates
         ``server.tup`` on downshifts, so deferring the attribute reads
-        to scrape time would misattribute batches)."""
+        to scrape time would misattribute batches).  With ``held`` the
+        caller releases the batch later than ``now + service_s`` (the
+        live gateway) and reports it through :meth:`on_release`, which
+        records the service and hop spans; only the queue span is
+        recorded here."""
         tup = server.tup
         n = len(batch)
         self._disp_log.append((server.app, tup.task, tup.batch,
@@ -224,6 +229,12 @@ class Instrumentation:
         if tr is None:
             return
         app, task = server.app, tup.task
+        if held:
+            for req in batch:
+                if tr.enabled_for(req.root_id):
+                    tr.record(f"{task}:queue", "queue", req.enqueue_t, now,
+                              app, req.root_id)
+            return
         end = now + service_s
         args = {"variant": tup.variant, "server": server.idx,
                 "batch": len(batch)}
@@ -235,6 +246,27 @@ class Instrumentation:
             tr.record(f"{task}:service", "service", now, end,
                       app, req.root_id)
             tr.record(task, "hop", req.enqueue_t, end, app,
+                      req.root_id, args)
+
+    def on_release(self, server: Any, batch: Sequence[Any], launch: float,
+                   now: float, service_s: float) -> None:
+        """A held batch (``on_dispatch(held=True)``) launched at
+        ``launch`` is released at ``now``: its service and hop spans end
+        here, and carry the backend's ``service_s`` as ``generate_s``."""
+        tr = self.tracer
+        if tr is None:
+            return
+        tup = server.tup
+        app, task = server.app, tup.task
+        gen = {"generate_s": service_s}
+        args = {"variant": tup.variant, "server": server.idx,
+                "batch": len(batch), **gen}
+        for req in batch:
+            if not tr.enabled_for(req.root_id):
+                continue
+            tr.record(f"{task}:service", "service", launch, now,
+                      app, req.root_id, gen)
+            tr.record(task, "hop", req.enqueue_t, now, app,
                       req.root_id, args)
 
     # -- scrape-time materialization ------------------------------------

@@ -106,6 +106,11 @@ class Counter(_Family):
         k = self._key(labels)
         self._samples[k] = self._samples.get(k, 0.0) + amount
 
+    def set_total(self, value: float, *labels: str) -> None:
+        """Mirror a running total that a scrape-time collector reads
+        from where it is kept (a loop's clock, an engine)."""
+        self._samples[self._key(labels)] = float(value)
+
 
 class Gauge(_Family):
     """Set-to-current-value sample per label set."""

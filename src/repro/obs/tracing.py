@@ -17,14 +17,25 @@ The tracer is bounded: ``max_events`` caps memory, ``sample_every``
 traces one in N roots so instrumentation stays off the hot path at high
 request rates (the overhead pin in ``BENCH_gateway.json`` is measured
 with sampling on).
+
+To lay the spans over a JAX profiler trace, the live gateway anchors the
+tracer at its start: ``anchor_ns`` is the profiler's host clock
+(``CLOCK_REALTIME``, ``time.time_ns``) at span time 0, and a span at
+``t`` seconds began at ``anchor_ns + t * wall_per_unit * 1e9`` on that
+clock; the export carries both under ``otherData``.  A profiler file
+gives its host events in nanoseconds after the ``profile_start_time``
+stat of its ``Task Environment`` plane, on the same clock.
+:class:`ProfilerInterval` writes back-to-back annotations onto that
+clock from code that never imports jax itself.
 """
 from __future__ import annotations
 
 import json
+import sys
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "validate_chrome_trace"]
+__all__ = ["ProfilerInterval", "Span", "Tracer", "validate_chrome_trace"]
 
 
 @dataclass
@@ -56,6 +67,8 @@ class Tracer:
     sample_every: int = 1
     spans: List[Span] = field(default_factory=list)
     dropped: int = 0
+    anchor_ns: Optional[int] = None     # profiler clock at span time 0
+    wall_per_unit: float = 1.0          # wall seconds per span second
 
     def enabled_for(self, root_id: int) -> bool:
         if self.sample_every <= 1:
@@ -93,11 +106,41 @@ class Tracer:
             events.append({"name": "process_name", "ph": "M", "pid": pid,
                            "tid": 0,
                            "args": {"name": app or "app"}})
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
+        out: dict = {"traceEvents": events, "displayTimeUnit": "ms"}
+        if self.anchor_ns is not None:
+            out["otherData"] = {"clock": "CLOCK_REALTIME",
+                                "anchor_ns": self.anchor_ns,
+                                "wall_per_unit": self.wall_per_unit}
+        return out
 
     def export(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f)
+
+
+class ProfilerInterval:
+    """One JAX profiler annotation open at a time, switched at the edges
+    of back-to-back intervals (a loop's states), so each interval is one
+    event on the profiler's clock.  A no-op until something else has
+    imported jax: the SimBackend paths stay jax-free.  An interval that
+    began before the profiler started is not recorded."""
+
+    __slots__ = ("_open",)
+
+    def __init__(self) -> None:
+        self._open: Any = None
+
+    def switch(self, name: Optional[str]) -> None:
+        """Close the open interval and, unless ``name`` is None, open
+        ``name``."""
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        jax = sys.modules.get("jax")
+        if name is not None and jax is not None:
+            ann = jax.profiler.TraceAnnotation(name)
+            ann.__enter__()
+            self._open = ann
 
 
 def validate_chrome_trace(obj: dict) -> List[dict]:

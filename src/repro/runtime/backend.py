@@ -22,7 +22,7 @@ import zlib
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import (Any, Dict, List, Mapping, Optional, Protocol, Sequence,
-                    TYPE_CHECKING, runtime_checkable)
+                    Tuple, TYPE_CHECKING, runtime_checkable)
 
 import numpy as np
 
@@ -109,7 +109,10 @@ class EngineBackend:
     generate at ``prompt_len`` compiles every batch size from 1 to
     ``max_batch``, so no compile lands inside a timed ``service_s``.
     Service time is the measured wall-clock of the batched greedy decode,
-    scaled by ``time_scale`` (sim-seconds per wall-second).
+    scaled by ``time_scale`` (sim-seconds per wall-second); a batch over
+    ``max_batch`` runs as several launches, and their times add up.
+    ``engine_phases`` and ``compile_totals`` hand the engines' running
+    totals to whoever publishes them (the gateway, at scrape time).
 
     ``pool_time_scale`` maps a ClusterSpec pool name to ITS scale so a
     heterogeneous CPU parity run reflects relative device speeds (e.g.
@@ -178,15 +181,30 @@ class EngineBackend:
         arch_name = task.variant(server.tup.variant).arch
         eng = self.engine_for(arch_name)
         vocab = eng.model.arch.vocab_size
-        b = min(max(len(batch), 1), eng.cfg.max_batch)
-        prompts = np.asarray(
-            rng.integers(0, vocab, size=(b, self.prompt_len)), np.int32)
-        t0 = time.monotonic()
-        eng.generate(prompts, max_new=self.max_new)
-        wall = time.monotonic() - t0
-        # a fixed-shape engine may need several launches for a big batch
-        launches = -(-len(batch) // eng.cfg.max_batch)
-        return wall * launches * self.scale_for(server.tup.pool)
+        # a fixed-shape engine serves a big batch in several launches;
+        # each one runs, and the service time is their summed wall time
+        n, cap = max(len(batch), 1), eng.cfg.max_batch
+        wall = 0.0
+        for lo in range(0, n, cap):
+            prompts = np.asarray(
+                rng.integers(0, vocab, size=(min(cap, n - lo),
+                                             self.prompt_len)), np.int32)
+            t0 = time.monotonic()
+            eng.generate(prompts, max_new=self.max_new)
+            wall += time.monotonic() - t0
+        return wall * self.scale_for(server.tup.pool)
+
+    def engine_phases(self) -> Dict[str, Tuple[int, float, float]]:
+        """Per served arch: its engine's generate calls, and their host
+        and sync seconds (``Engine.host_s`` / ``Engine.sync_s``)."""
+        return {a: (e.generates, e.host_s, e.sync_s)
+                for a, e in self._engines.items()}
+
+    def compile_totals(self) -> Tuple[int, float]:
+        """The process's XLA compiles and their seconds, persistent-cache
+        loads included (``serving.engine.compile_totals``)."""
+        from repro.serving.engine import compile_totals
+        return compile_totals()
 
     def on_capacity_change(self, servers):
         pass

@@ -5,6 +5,7 @@ import os
 import shutil
 import subprocess
 import sys
+import time
 from pathlib import Path
 
 import jax
@@ -86,6 +87,37 @@ def test_engine_backend_no_compile_in_service(reduced_backend):
         for b in range(1, reduced_backend.max_batch + 1):
             assert reduced_backend.service_s(srv, [None] * b, 0.0, rng) > 0
     assert seen.n == 0
+
+
+def test_engine_backend_runs_every_launch_of_a_big_batch(reduced_backend,
+                                                        monkeypatch):
+    """A batch over ``max_batch`` runs in launches of ``max_batch``, each
+    one served, and its service time is their summed wall time."""
+    g = chip_smoke.smoke_graph(reduced_backend.prompt_len,
+                               reduced_backend.max_new)
+    cfg = Planner(g, Profiler(g), s_avail=chip_smoke.S_AVAIL,
+                  max_tuples_per_task=32, bb_nodes=4,
+                  bb_time_s=1.0).plan(chip_smoke.PLAN_RPS)
+    reduced_backend.bind(g, cfg)
+    srv = Server(cfg.instances()[0][0], 0)
+    eng = reduced_backend.engine_for(
+        g.tasks[srv.tup.task].variant(srv.tup.variant).arch)
+    sizes, walls = [], []
+    generate = eng.generate
+
+    def timed(prompts, **kw):
+        t0 = time.monotonic()
+        out = generate(prompts, **kw)
+        walls.append(time.monotonic() - t0)
+        sizes.append(len(prompts))
+        return out
+
+    monkeypatch.setattr(eng, "generate", timed)
+    mb = reduced_backend.max_batch
+    service = reduced_backend.service_s(srv, [None] * (2 * mb + 1), 0.0,
+                                        np.random.default_rng(0))
+    assert sizes == [mb, mb, 1]
+    assert sum(walls) <= service <= sum(walls) * 1.05 + 1e-3
 
 
 def test_compile_watch_counts_a_compile():

@@ -1,5 +1,6 @@
 """In-process serving engine + batcher on a reduced model (CPU)."""
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +37,24 @@ def test_generate_greedy_matches_stepwise(engine):
             logits, cache = eng.model.decode_step(
                 eng.params, cache, jnp.int32(12 + i), tok)
             tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+
+
+def test_generate_splits_its_wall_time_into_host_and_sync(engine):
+    """The engine's running totals: one generate per call, and host plus
+    sync seconds make up each call's wall time."""
+    arch, eng = engine
+    prompts = np.zeros((3, 10), np.int32)
+    eng.generate(prompts, max_new=4)            # compiles batch 3
+    n0, host0, sync0 = eng.generates, eng.host_s, eng.sync_s
+    wall = 0.0
+    for _ in range(4):
+        t0 = time.perf_counter()
+        eng.generate(prompts, max_new=4)
+        wall += time.perf_counter() - t0
+    assert eng.generates - n0 == 4
+    assert eng.sync_s > sync0 and eng.host_s > host0
+    assert (eng.host_s - host0) + (eng.sync_s - sync0) == \
+        pytest.approx(wall, rel=0.05)
 
 
 def test_batcher_launches_on_full_batch(engine):
