@@ -7,8 +7,13 @@ the wall clock: the same per-``app::task`` queues, task-level batching
 per-app :class:`~repro.core.frontend.Frontend` deadline stamping as
 :class:`~repro.runtime.cluster.ClusterRuntime` — but requests arrive by
 ``await gw.submit(app)`` instead of a Scenario, dispatchers are asyncio
-tasks, and service times from the :class:`ExecutionBackend` are slept
-in real time.
+tasks, and a launched batch is released at launch + its service time
+from the :class:`ExecutionBackend`.  The release sleeps only what that
+service exceeds the time already spent: all of it for a backend that
+predicts (``SimBackend`` returns at once), nothing for one that ran the
+batch while the loop waited (``EngineBackend`` returns the wall time of
+its generate), and the difference where a backend reports a modelled
+device slower than the one it ran on.
 
 The gateway clock runs in the runtime's *simulated* seconds: ``now()``
 is wall time divided by ``time_scale``, and sleeps multiply back.  All
@@ -72,19 +77,20 @@ class _LoopStates:
     """Which state the gateway's event loop is in, and the wall seconds
     each state has held while the gateway ran.  In order of precedence:
     ``generate`` while the loop is inside ``backend.service_s``;
-    ``replay`` while a ``_serve`` sleep is pending (a finished batch's
-    results are held back); ``batch_wait`` while a task queue holds a
-    request; ``empty`` otherwise.  The gateway moves the three running
-    counts at its own events and calls :meth:`update`, which reads the
-    clock only where the state changes, and opens one ``gw.<state>``
-    profiler annotation per state interval."""
+    ``replay`` while a ``_serve`` task is pending (a launched batch not
+    yet released: its service time not yet over, or its release's own
+    hop completions and fan-out); ``batch_wait`` while a task queue
+    holds a request; ``empty`` otherwise.  The gateway moves the three
+    running counts at its own events and calls :meth:`update`, which
+    reads the clock only where the state changes, and opens one
+    ``gw.<state>`` profiler annotation per state interval."""
 
     __slots__ = ("generating", "replays", "queued", "seconds", "state",
                  "since", "_spans")
 
     def __init__(self) -> None:
         self.generating = False
-        self.replays = 0                 # pending _serve sleeps
+        self.replays = 0                 # pending _serve tasks
         self.queued = 0                  # requests in every task queue
         self.seconds = [0.0] * len(LOOP_STATES)
         self.state = len(LOOP_STATES) - 1
@@ -461,7 +467,7 @@ class AsyncGateway:
             srv.busy_until = now + service
             srv.served += len(batch)
             if self.hooks is not None:
-                # the batch is released by _serve, one sleep later
+                # the batch is released by _serve, at now + service
                 self.hooks.on_dispatch(srv, batch, now, service, len(q),
                                        held=True)
             asyncio.get_running_loop().create_task(
@@ -474,7 +480,11 @@ class AsyncGateway:
         loop.replays += 1
         loop.update()
         try:
-            await asyncio.sleep(service * self.time_scale)
+            # release at launch + service: what a blocking backend's call
+            # already spent is not slept again
+            rest = launch + service - self.now()
+            if rest > 0:
+                await asyncio.sleep(rest * self.time_scale)
             now = self.now()
             srv.busy_until = now
             if self.hooks is not None:
@@ -580,7 +590,8 @@ class AsyncGateway:
         loop_s = registry.counter(
             "jigsaw_gateway_loop_seconds_total",
             "Gateway event-loop wall seconds by state: generate (inside "
-            "backend.service_s), replay (a finished batch held back), "
+            "backend.service_s), replay (a launched batch not yet "
+            "released), "
             "batch_wait (a task queue holds a request), empty",
             ("state",))
         phases = getattr(self.backend, "engine_phases", None)

@@ -205,10 +205,12 @@ class Instrumentation:
         one shot.  The scalars are captured NOW (the ladder mutates
         ``server.tup`` on downshifts, so deferring the attribute reads
         to scrape time would misattribute batches).  With ``held`` the
-        caller releases the batch later than ``now + service_s`` (the
-        live gateway) and reports it through :meth:`on_release`, which
-        records the service and hop spans; only the queue span is
-        recorded here."""
+        caller releases the batch itself and reports it through
+        :meth:`on_release`, which records the service and hop spans; only
+        the queue span is recorded here.  The live gateway releases at
+        ``now + service_s``, sleeping only what ``service_s`` exceeds the
+        time already spent, or, where its loop is busy then, as soon as
+        the loop is free."""
         tup = server.tup
         n = len(batch)
         self._disp_log.append((server.app, tup.task, tup.batch,
