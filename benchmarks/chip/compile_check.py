@@ -1,17 +1,17 @@
-"""Compile a configuration's programs for a described TPU v5e chip, with no
-chip attached, and print what each needs of the chip's memory.
+"""Compile a cell's programs for a described TPU v5e chip, with no chip
+attached, and print what each needs of the chip's memory.
 
-    JAX_PLATFORMS=cpu python3 benchmarks/chip/compile_check.py <config> \\
+    JAX_PLATFORMS=cpu python3 benchmarks/chip/compile_check.py <cell> \\
         [--batch 4] [--prompt-len 128] [--max-seq 256]
 
-For every model of ``configs/<config>.json``: the program's own weight
+For every model of the cell's configuration: the program's own weight
 init (``jax.jit(Model.init)``, which ``EngineBackend`` runs first), the
-benchmark's seeded draw (``weights.served_params``), and the engine's
-prefill and decode programs at the cell's shapes. What the compiler
-refuses here costs no chip time. Nothing runs, so this gives no times.
+benchmark's seeded draw (its family's ``served_params``), and the
+engine's prefill and decode programs at the cell's shapes. What the
+compiler refuses here costs no chip time. Nothing runs, so this gives no
+times.
 """
 import argparse
-import json
 import os
 import sys
 from pathlib import Path
@@ -22,7 +22,7 @@ ROOT = HERE.parents[1]
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("config")
+    ap.add_argument("workload")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=128)
     ap.add_argument("--max-seq", type=int, default=256)
@@ -48,10 +48,10 @@ def main(argv=None) -> int:
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
     spec = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)
-    cfg = json.loads((HERE / "configs" / f"{args.config}.json").read_text())
+    cell = H.load_cell(ROOT, args.workload)
     B, S = args.batch, args.prompt_len
     total = 0
-    with H.registered(cfg) as dims:
+    with H.registered(cell) as dims:
         for name, m in dims.items():
             model = Model(ARCHS[name], ShardingPolicy(mesh=None),
                           param_dtype=jnp.bfloat16)
@@ -66,7 +66,7 @@ def main(argv=None) -> int:
                 lambda p, t: eng.prefill(p, t)[1], params, tokens))
             progs = {
                 "program init": jax.jit(model.init).lower(key),
-                "seeded draw": W.served_params.lower(m, words),
+                "seeded draw": W.family_of(m).served_params.lower(m, words),
                 "prefill": eng.prefill.lower(params, tokens),
                 "decode": eng.decode.lower(
                     params, cache, jax.ShapeDtypeStruct((), jnp.int32,

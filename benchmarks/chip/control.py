@@ -9,7 +9,7 @@ does at the cell's own size and load: load the seed's weights into the
 deployment (built once, then reused), serve a short open-loop window, free
 the weights, and run the reference over a sample of what was served. It
 then puts the reference in a lower precision in the program's place
-(``reference.dense`` with ``quant``) and reads, at each position of the
+(the family's ``logits`` with ``quant``) and reads, at each position of the
 same prompts and served tokens, the gap of the token that it ranks first.
 Each reading is judged as a run judges its own (``harness.judge``, with
 the configuration's ``limits``): the program has to come out ``correct``
@@ -99,7 +99,7 @@ def main(argv=None) -> int:
     seeds = [int(s) for s in args.seeds.split(",")]
     quants = [q for q in args.quants.split(",") if q]
     t0 = time.monotonic()
-    with H.registered(cell.config) as dims:
+    with H.registered(cell) as dims:
         outs = list(readings(cell, dims, seeds, seconds, quants,
                              lambda m: print(m, flush=True)))
     summary = {"workload": cell.name, "seconds": seconds,

@@ -1,8 +1,9 @@
 """The comparison that decides ``correct``.
 
 After the window, a sample of the requests each model served, drawn from
-the seed, is run through the plain float32 reference over its prompt and
-its served tokens. For every served token the gap is how far the
+the seed, is run through the plain float32 reference of the model's family
+(``logits`` of ``families/<family>.py``) over its prompt and its served
+tokens. For every served token the gap is how far the
 reference's logit of that token lies below the reference's best at that
 position. Greedy decoding in bf16 serves a token the reference ranks
 second only where the two lie within the bf16 rounding of each other; a
@@ -18,14 +19,13 @@ served tokens, the gap of the token that it puts first.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.chip.reference import dense
-from benchmarks.chip.weights import DenseDims
+from benchmarks.chip.weights import family_of
 
 Call = Tuple[str, np.ndarray, np.ndarray]      # arch, prompts, served tokens
 
@@ -47,13 +47,14 @@ def sample_rows(calls: Sequence[Call], arch: str, seed: int, rows: int
     return P[pick], T[pick]
 
 
-def reference_logits(m: DenseDims, words: np.ndarray, prompts: np.ndarray,
+def reference_logits(m: Any, words: np.ndarray, prompts: np.ndarray,
                      served: np.ndarray, quant: Optional[str] = None
                      ) -> jax.Array:
     """Logits ``[B, N, V]`` at the positions that chose the ``N`` served
     tokens: the last prompt position and each served token but the last."""
     tokens = np.concatenate([prompts, served[:, :-1]], axis=1)
-    return dense.logits(m, words, tokens, prompts.shape[1] - 1, quant)
+    return family_of(m).logits(m, words, tokens, prompts.shape[1] - 1,
+                               quant)
 
 
 @jax.jit
@@ -81,7 +82,7 @@ def gap_numbers(gaps: np.ndarray, arch: str) -> Dict[str, float]:
             f"miss_share.{arch}": float((gaps > 0).mean())}
 
 
-def compare_calls(dims: Dict[str, DenseDims], words: np.ndarray,
+def compare_calls(dims: Dict[str, Any], words: np.ndarray,
                   calls: List[Call], seed: int, rows: int,
                   prompt_len: int) -> Dict[str, float]:
     """The gap numbers of each model's sample."""

@@ -4,9 +4,13 @@
 A cell ``<config>.<mix>`` names a configuration file
 (``configs/<config>.json``: the models at their published widths, the app
 they serve, the deployment's sizes and the limits of the comparison) and a
-traffic mix (``traffic/<mix>.json``: arrivals, rate, lengths). A per-layer
-metric ``<name>[.<part>]`` is read by ``metrics/<name>.py``. Adding a cell,
-a configuration or a metric adds files and entries, never edits these.
+traffic mix (``traffic/<mix>.json``: arrivals, rate, lengths). The
+configuration names its model family, whose module
+(``families/<family>.py``, see ``families/__init__.py``) draws the weights,
+computes the plain reference and counts the work. A per-layer metric
+``<name>[.<part>]`` is read by ``metrics/<name>.py``. Adding a cell, a
+configuration, a family or a metric adds files and entries, never edits
+these.
 
 The system under test is the repo's own serving path:
 ``build_demo_gateway`` plans the app with the MILP and wraps the plan in
@@ -24,6 +28,7 @@ import importlib.util
 import json
 import math
 import shutil
+import sys
 import tempfile
 import time
 from pathlib import Path
@@ -41,17 +46,6 @@ HOST_SPANS = ("window", "generate|", "service_s|", "plan")
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-
-# Hugging Face config key -> the repo's ArchConfig field
-_ARCH_FIELDS = {
-    "hidden_size": "d_model", "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings", "attention_bias": "qkv_bias",
-}
-
 
 class BenchError(RuntimeError):
     """The cell cannot be run as its files describe it."""
@@ -128,6 +122,7 @@ class Cell:
     home: Path                           # the benchmark's own directory
     chips: int
     config: Dict[str, Any]
+    family: Any                          # the module families/<family>.py
     traffic: Dict[str, Any]
     per_layer: List[Dict[str, Any]]      # BENCHMARK.json entries that apply
     end_to_end: List[Dict[str, Any]]
@@ -152,32 +147,53 @@ def load_cell(root: Path, workload: str) -> Cell:
     configs = {c["name"]: c for c in bench["configs"]}
     home = root / Path(bench["command"][1]).parent
     config = json.loads((root / configs[w["config"]]["file"]).read_text())
+    if "family" not in config:
+        raise BenchError(f"configuration {w['config']!r} names no family")
+    family = load_family(home, config["family"])
     traffic = json.loads((home / "traffic" / f"{w['traffic']}.json")
                          .read_text())
     if traffic.get("arrivals") not in TG.ARRIVALS:
         raise BenchError(f"traffic {w['traffic']!r}: arrivals "
                          f"{traffic.get('arrivals')!r} not in {TG.ARRIVALS}")
-    return Cell(workload, home, int(w["chips"]), config, traffic,
+    return Cell(workload, home, int(w["chips"]), config, family, traffic,
                 [m for m in bench["per_layer"] if _applies(m, workload)],
                 [m for m in bench["end_to_end"] if _applies(m, workload)])
 
 
+def load_family(home: Path, name: str) -> Any:
+    """The module ``families/<name>.py`` of the benchmark at ``home``,
+    loaded once per process: every ``Dims`` of a family is then of one
+    class, which ``weights.family_of`` maps back to the module."""
+    path = home / "families" / f"{name}.py"
+    if not str(name).isidentifier() or not path.is_file():
+        raise BenchError(f"family {name!r}: no module {path}")
+    modname = f"benchmarks.chip.families.{name}"
+    if modname not in sys.modules:
+        spec = importlib.util.spec_from_file_location(modname, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[modname] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[modname]
+            raise
+    return sys.modules[modname]
+
+
 @contextlib.contextmanager
-def registered(config: Mapping[str, Any]) -> Iterator[Dict[str, W.DenseDims]]:
-    """The config's model as a repo arch under its ``served_as`` name, for
+def registered(cell: Cell) -> Iterator[Dict[str, Any]]:
+    """The cell's model as a repo arch under its ``served_as`` name, for
     the planner's profiler and the engines; a name the repo already has
-    must describe the same arch. Yields the benchmark's own dims of the
-    model by that name, and unregisters what it added on exit."""
+    must describe the same arch. Yields the family's dims of the model by
+    that name, and unregisters what it added on exit."""
     from repro.configs import ARCHS
+    config = cell.config
     name = config["served_as"]
-    arch = dataclasses.replace(ARCHS[config["repo_arch"]], name=name, **{
-        f: config[k] for k, f in _ARCH_FIELDS.items() if k in config})
-    if config.get("hidden_act", "silu") != arch.mlp_activation:
-        raise BenchError(f"{name}: hidden_act {config['hidden_act']} but "
-                         f"the repo serves {arch.mlp_activation}")
-    dims = W.DenseDims.from_keys(name, config)
-    if dims.head_dim != arch.head_dim:
-        raise BenchError(f"{name}: head_dim differs")
+    dims = cell.family.Dims.from_keys(name, config)
+    try:
+        arch = cell.family.repo_arch(dims, config)
+    except ValueError as e:
+        raise BenchError(f"{name}: {e}") from e
     added = name not in ARCHS
     if added:
         ARCHS[name] = arch
@@ -227,8 +243,7 @@ def bench_backend_class():
 
     @dataclasses.dataclass
     class BenchBackend(EngineBackend):
-        dims: Dict[str, W.DenseDims] = dataclasses.field(
-            default_factory=dict)
+        dims: Dict[str, Any] = dataclasses.field(default_factory=dict)
         words: Any = None
         # (arch, prompts [b, S], served tokens [b, max_new]) per generate
         calls: List[Tuple[str, np.ndarray, np.ndarray]] = dataclasses.field(
@@ -276,8 +291,9 @@ def bench_backend_class():
             eng.params = None
             # wait for the draw: an old tree is freed only once no program
             # in flight still uses it
+            m = self.dims[arch]
             eng.params = jax.block_until_ready(
-                W.served_params(self.dims[arch], self.words))
+                W.family_of(m).served_params(m, self.words))
             got = jax.tree.map(lambda a: (a.shape, a.dtype), eng.params)
             if want is not None and got != want:
                 raise BenchError(f"{arch}: the benchmark's weights are not "
@@ -316,7 +332,7 @@ class Deployment:
                       for s in self.gateway.servers)
 
 
-def deploy(cell: Cell, dims: Dict[str, W.DenseDims], seed: int,
+def deploy(cell: Cell, dims: Dict[str, Any], seed: int,
            backend: Any = None) -> Deployment:
     """Plan, build and warm up; with ``backend``, reuse its engines and
     load the new seed's weights. The gateway takes the config's
@@ -533,7 +549,7 @@ class Execution:
 class Context:
     """What a per-layer metric's reader may read."""
     cell: Cell
-    dims: Dict[str, W.DenseDims]
+    dims: Dict[str, Any]
     window: Window
     peak: Dict[str, float]
     memory_peak_bytes: int
@@ -660,7 +676,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     dev = device_info()
     peak = peaks.peak(dev["kind"]) if dev["platform"] == "tpu" else {}
     tr = cell.traffic
-    with registered(cell.config) as dims:
+    with registered(cell) as dims:
         dep = deploy(cell, dims, seed)
         log(f"plan: rate {cell.rate_rps} rps (knee "
             f"{cell.config['knee_rps']} x {tr['rate_of_knee']}), s_avail "

@@ -100,7 +100,7 @@ def main(argv=None) -> int:
     seeds = [int(s) for s in args.seeds.split(",")]
     backend = None
     verdicts = []
-    with H.registered(cell.config) as dims:
+    with H.registered(cell) as dims:
         for rate in (float(r) for r in args.rates.split(",")):
             at = dataclasses.replace(
                 cell, config={**cell.config, "knee_rps": rate},

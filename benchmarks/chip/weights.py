@@ -1,103 +1,25 @@
-"""Random weights of a dense decoder, drawn from the run's seed.
+"""Random weights drawn from the run's seed, shared by every model family.
 
 The benchmark, not the program, makes the weights: the served engine is
 given them in the type it serves (bfloat16), and the plain reference draws
 the same values again, layer by layer, without taking anything the
 program holds. Every leaf of every layer has a key of its own, folded from
 the seed, the leaf's index and the layer, so one layer can be drawn alone
-and equals the same layer of the stacked draw.
-
-The tree is the layout the repo's dense ``Model`` takes
-(``embed``, ``final_norm``, ``lm_head`` when untied, and ``blocks`` with a
-leading layer axis). Norm weights are offsets, as the program applies
-them (``x * (1 + w)``). Biases and norm offsets are drawn non-zero so that
-the comparison exercises them.
+and equals the same layer of the stacked draw. Each family
+(``families/<family>.py``) keeps its own index of leaf names and its own
+tree; the seed's words, the key fold and the draw are the same for all.
 """
 from __future__ import annotations
 
-import dataclasses
-from functools import partial
-from typing import Any, Dict, Mapping, Tuple
+import sys
+from types import ModuleType
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 SERVED_DTYPE = jnp.bfloat16
-NORM_SCALE = 0.1       # norm offsets: scales of 1 +- 0.1
-BIAS_SCALE = 0.5       # q/k/v are about unit-scale; a bias of half that
-EMBED_SCALE = 0.02
-
-
-@dataclasses.dataclass(frozen=True)
-class DenseDims:
-    """The shape of a dense decoder, read from a configuration file."""
-    name: str
-    vocab: int
-    d_model: int
-    layers: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    qkv_bias: bool
-    tied: bool
-    rope_theta: float
-    norm_eps: float
-
-    @classmethod
-    def from_keys(cls, name: str, keys: Mapping[str, Any]) -> "DenseDims":
-        """From the Hugging Face ``config.json`` keys of a model."""
-        heads = int(keys["num_attention_heads"])
-        return cls(
-            name=name,
-            vocab=int(keys["vocab_size"]),
-            d_model=int(keys["hidden_size"]),
-            layers=int(keys["num_hidden_layers"]),
-            heads=heads,
-            kv_heads=int(keys["num_key_value_heads"]),
-            head_dim=int(keys.get("head_dim")
-                         or keys["hidden_size"] // heads),
-            d_ff=int(keys["intermediate_size"]),
-            qkv_bias=bool(keys["attention_bias"]),
-            tied=bool(keys["tie_word_embeddings"]),
-            rope_theta=float(keys["rope_theta"]),
-            norm_eps=float(keys["rms_norm_eps"]))
-
-    def layer_shapes(self) -> Dict[str, Tuple[Tuple[int, ...], float]]:
-        """Per-layer leaf -> (shape without the layer axis, scale)."""
-        d, H, KV, hd, f = (self.d_model, self.heads, self.kv_heads,
-                           self.head_dim, self.d_ff)
-        out = {
-            "attn_norm": ((d,), NORM_SCALE),
-            "wq": ((d, H, hd), d ** -0.5),
-            "wk": ((d, KV, hd), d ** -0.5),
-            "wv": ((d, KV, hd), d ** -0.5),
-            "wo": ((H, hd, d), (H * hd) ** -0.5),
-            "mlp_norm": ((d,), NORM_SCALE),
-            "wg": ((d, f), d ** -0.5),
-            "wu": ((d, f), d ** -0.5),
-            "wd": ((f, d), f ** -0.5),
-        }
-        if self.qkv_bias:
-            out.update(bq=((H, hd), BIAS_SCALE), bk=((KV, hd), BIAS_SCALE),
-                       bv=((KV, hd), BIAS_SCALE))
-        return out
-
-    def layer_params(self) -> int:
-        return sum(int(np.prod(s)) for s, _ in self.layer_shapes().values())
-
-    def param_count(self) -> int:
-        head = 0 if self.tied else self.vocab * self.d_model
-        return (self.vocab * self.d_model + head + self.d_model
-                + self.layers * self.layer_params())
-
-
-# a fixed index per leaf name: the key of a leaf never depends on which
-# other leaves a model has
-_LEAF_INDEX = {n: i for i, n in enumerate(
-    ("embed", "final_norm", "lm_head", "attn_norm", "wq", "wk", "wv", "wo",
-     "bq", "bk", "bv", "mlp_norm", "wg", "wu", "wd"))}
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -108,45 +30,19 @@ def seed_words(seed: int) -> np.ndarray:
     return np.array([seed & 0xFFFFFFFF, seed >> 32], np.uint32)
 
 
-def _leaf_key(words: jax.Array, name: str) -> jax.Array:
+def leaf_key(words: jax.Array, index: int) -> jax.Array:
+    """The key of the leaf with a family's fixed ``index``: it never
+    depends on which other leaves a model has."""
     root = jax.random.fold_in(jax.random.key(words[0]), words[1])
-    return jax.random.fold_in(root, _LEAF_INDEX[name])
+    return jax.random.fold_in(root, index)
 
 
-def _draw(key: jax.Array, shape: Tuple[int, ...], scale: float) -> jax.Array:
-    return (jax.random.normal(key, shape, jnp.float32)
-            * scale).astype(SERVED_DTYPE)
+def draw(key: jax.Array, shape: Tuple[int, ...], scale: float,
+         dtype: Any = SERVED_DTYPE) -> jax.Array:
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
-def _layer(m: DenseDims, words: jax.Array, layer: jax.Array
-           ) -> Dict[str, jax.Array]:
-    return {n: _draw(jax.random.fold_in(_leaf_key(words, n), layer), s, sc)
-            for n, (s, sc) in m.layer_shapes().items()}
-
-
-@partial(jax.jit, static_argnums=0)
-def layer_weights(m: DenseDims, words: jax.Array, layer: jax.Array
-                  ) -> Dict[str, jax.Array]:
-    """One layer's leaves, equal to that layer of :func:`served_params`."""
-    return _layer(m, words, layer)
-
-
-@partial(jax.jit, static_argnums=0)
-def table_weights(m: DenseDims, words: jax.Array) -> Dict[str, jax.Array]:
-    """``embed``, ``final_norm`` and, when untied, ``lm_head``."""
-    d, V = m.d_model, m.vocab
-    out = {"embed": _draw(_leaf_key(words, "embed"), (V, d), EMBED_SCALE),
-           "final_norm": _draw(_leaf_key(words, "final_norm"), (d,),
-                               NORM_SCALE)}
-    if not m.tied:
-        out["lm_head"] = _draw(_leaf_key(words, "lm_head"), (d, V),
-                               d ** -0.5)
-    return out
-
-
-@partial(jax.jit, static_argnums=0)
-def served_params(m: DenseDims, words: jax.Array) -> Dict[str, Any]:
-    """The whole tree in one program, every layer stacked."""
-    layers = jnp.arange(m.layers, dtype=jnp.uint32)
-    blocks = jax.vmap(lambda l: _layer(m, words, l))(layers)
-    return {**table_weights(m, words), "blocks": blocks}
+def family_of(dims: Any) -> ModuleType:
+    """The family module whose ``Dims`` made ``dims``: its weights, its
+    reference and its work counts."""
+    return sys.modules[type(dims).__module__]

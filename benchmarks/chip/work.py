@@ -1,59 +1,29 @@
-"""Operations and bytes that a dense decoder step needs, from its shapes.
+"""Operations and bytes that one prefill or decode step needs, from the
+model's shapes, as its family counts them (``prefill_flops``,
+``decode_flops`` and ``decode_bytes`` of ``families/<family>.py``).
 
-These count the work itself, whatever implements it, so that a share of
-a peak computed from them cannot pass 100% however a later kernel does the
-work:
-
-- operations: two per multiply-add of every projection and the gated
-  MLP, causal attention over the positions each query may see, and the
-  head at the positions whose logits the step returns (the last prompt
-  position in prefill, the one new position in decode);
-- decode bytes: every weight read once (the embedding only at the rows
-  the batch looks up, unless the head is tied to it), the key and value
-  cache read up to ``cache_len``, and one position of it written. What the
-  repo's code moves beyond that (a one-hot rewrite of the whole cache,
-  attention over ``max_seq``) is not work the step needs.
+The counts are of the work itself, whatever implements it, so that a
+share of a peak computed from them cannot pass 100% however a later
+kernel does the work.
 """
 from __future__ import annotations
 
-from benchmarks.chip.weights import DenseDims
+from typing import Any
 
-BF16_BYTES = 2
-
-
-def _proj_macs_per_token(m: DenseDims) -> int:
-    """Multiply-adds of the projections and MLP of all layers, per token."""
-    d, H, KV, hd, f = m.d_model, m.heads, m.kv_heads, m.head_dim, m.d_ff
-    per_layer = d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * f
-    return m.layers * per_layer
+from benchmarks.chip.weights import family_of
 
 
-def prefill_flops(m: DenseDims, batch: int, seq: int) -> float:
+def prefill_flops(m: Any, batch: int, seq: int) -> float:
     """One prefill of ``batch`` prompts of ``seq`` tokens."""
-    proj = 2.0 * batch * seq * _proj_macs_per_token(m)
-    pairs = seq * (seq + 1) / 2                        # causal (q, k) pairs
-    attn = 2.0 * 2.0 * batch * m.layers * m.heads * m.head_dim * pairs
-    head = 2.0 * batch * m.d_model * m.vocab           # last position only
-    return proj + attn + head
+    return family_of(m).prefill_flops(m, batch, seq)
 
 
-def decode_flops(m: DenseDims, batch: int, cache_len: int) -> float:
+def decode_flops(m: Any, batch: int, cache_len: int) -> float:
     """One decode step: one new token per row against ``cache_len``
-    cached positions (it attends to ``cache_len + 1``)."""
-    proj = 2.0 * batch * _proj_macs_per_token(m)
-    attn = 2.0 * 2.0 * batch * m.layers * m.heads * m.head_dim \
-        * (cache_len + 1)
-    head = 2.0 * batch * m.d_model * m.vocab
-    return proj + attn + head
+    cached positions."""
+    return family_of(m).decode_flops(m, batch, cache_len)
 
 
-def decode_bytes(m: DenseDims, batch: int, cache_len: int,
-                 dtype_bytes: int = BF16_BYTES) -> float:
+def decode_bytes(m: Any, batch: int, cache_len: int) -> float:
     """Least bytes one decode step moves to and from HBM."""
-    weights = m.layers * m.layer_params() + m.d_model        # + final norm
-    weights += m.vocab * m.d_model                           # head table
-    if not m.tied:
-        weights += batch * m.d_model                         # looked-up rows
-    kv_row = 2 * m.layers * m.kv_heads * m.head_dim          # k and v
-    cache = batch * kv_row * (cache_len + 1)                 # read + write
-    return float(dtype_bytes) * (weights + cache)
+    return family_of(m).decode_bytes(m, batch, cache_len)

@@ -197,7 +197,7 @@ def test_the_control_fails_where_the_program_passes(tiny):
     judges what it served."""
     from benchmarks.chip import control
     cell, _ = tiny
-    with H.registered(cell.config) as dims:
+    with H.registered(cell) as dims:
         outs = list(control.readings(cell, dims, [SEED + 7, 11, 2 ** 40],
                                      WINDOW_S, ["fp8"], lambda m: None))
     for o in outs:
@@ -266,7 +266,7 @@ def test_the_plan_is_the_same_across_seeds(cell_name):
     from repro.gateway import build_demo_gateway
     cell = H.load_cell(REPO, cell_name)
     plans = []
-    with H.registered(cell.config):
+    with H.registered(cell):
         graph = H.app_graph(cell.config, cell.traffic)
         for seed in (SEED, 7):
             gw, _ = build_demo_gateway(

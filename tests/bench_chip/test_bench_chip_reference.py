@@ -1,7 +1,11 @@
-"""The chip benchmark's yardstick at reduced sizes on the CPU: the seeded
-weights, the plain float32 reference against the repo's ``Model``, and
-the operation and byte counts against shapes worked out by hand."""
+"""The chip benchmark's yardstick for the dense family at reduced sizes on
+the CPU: the seeded weights, the plain float32 reference against the
+repo's ``Model``, and the operation and byte counts against shapes worked
+out by hand; the draw and the counts pinned to what they were before the
+family had a module of its own."""
 import dataclasses
+import hashlib
+import json
 import sys
 from pathlib import Path
 
@@ -14,7 +18,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 from benchmarks.chip import correct as C  # noqa: E402
 from benchmarks.chip import weights as W  # noqa: E402
 from benchmarks.chip import work  # noqa: E402
-from benchmarks.chip.reference import dense  # noqa: E402
+from benchmarks.chip.families import dense  # noqa: E402
 from repro.configs import ARCHS  # noqa: E402
 from repro.models import Model  # noqa: E402
 from repro.sharding.policy import ShardingPolicy  # noqa: E402
@@ -38,7 +42,7 @@ SMALL = {
 
 def _small(repo_arch):
     keys = SMALL[repo_arch]
-    m = W.DenseDims.from_keys(f"{repo_arch}-small", keys)
+    m = dense.DenseDims.from_keys(f"{repo_arch}-small", keys)
     arch = dataclasses.replace(
         ARCHS[repo_arch], name=m.name, num_layers=m.layers,
         d_model=m.d_model, num_heads=m.heads, num_kv_heads=m.kv_heads,
@@ -53,7 +57,7 @@ def test_served_tree_is_the_models(repo_arch):
     m, arch = _small(repo_arch)
     model = Model(arch, ShardingPolicy(mesh=None), param_dtype=jnp.bfloat16)
     want = jax.eval_shape(model.init, jax.random.key(0))
-    got = jax.eval_shape(W.served_params, m, W.seed_words(SEED))
+    got = jax.eval_shape(dense.served_params, m, W.seed_words(SEED))
     assert jax.tree.structure(got) == jax.tree.structure(want)
     assert jax.tree.map(lambda a: (a.shape, a.dtype), got) == \
         jax.tree.map(lambda a: (a.shape, a.dtype), want)
@@ -64,9 +68,9 @@ def test_served_tree_is_the_models(repo_arch):
 def test_one_layer_equals_the_stacked_draw(repo_arch):
     m, _ = _small(repo_arch)
     words = W.seed_words(SEED)
-    full = W.served_params(m, words)
+    full = dense.served_params(m, words)
     for layer in range(m.layers):
-        one = W.layer_weights(m, words, np.uint32(layer))
+        one = dense.layer_weights(m, words, np.uint32(layer))
         for name, leaf in one.items():
             np.testing.assert_array_equal(
                 np.asarray(leaf, np.float32),
@@ -75,9 +79,9 @@ def test_one_layer_equals_the_stacked_draw(repo_arch):
 
 def test_seeds_give_other_weights_and_the_same_seed_the_same():
     m, _ = _small("granite-3-2b")
-    a = W.table_weights(m, W.seed_words(SEED))["embed"]
-    b = W.table_weights(m, W.seed_words(SEED))["embed"]
-    c = W.table_weights(m, W.seed_words(SEED + 2 ** 32))["embed"]
+    a = dense.table_weights(m, W.seed_words(SEED))["embed"]
+    b = dense.table_weights(m, W.seed_words(SEED))["embed"]
+    c = dense.table_weights(m, W.seed_words(SEED + 2 ** 32))["embed"]
     np.testing.assert_array_equal(np.asarray(a, np.float32),
                                   np.asarray(b, np.float32))
     assert not np.array_equal(np.asarray(a, np.float32),
@@ -90,7 +94,7 @@ def test_reference_matches_model_forward(repo_arch):
     m, arch = _small(repo_arch)
     words = W.seed_words(SEED)
     params = jax.tree.map(lambda a: a.astype(jnp.float32),
-                          W.served_params(m, words))
+                          dense.served_params(m, words))
     model = Model(arch, ShardingPolicy(mesh=None), param_dtype=jnp.float32)
     tokens = np.random.default_rng(3).integers(0, m.vocab, (3, 11),
                                                dtype=np.int32)
@@ -128,7 +132,7 @@ def test_lower_precision_moves_the_logits(quant):
 
 
 def test_counts_match_shapes_worked_by_hand():
-    m = W.DenseDims("hand", vocab=10, d_model=4, layers=1, heads=2,
+    m = dense.DenseDims("hand", vocab=10, d_model=4, layers=1, heads=2,
                     kv_heads=1, head_dim=2, d_ff=8, qkv_bias=False,
                     tied=True, rope_theta=1e4, norm_eps=1e-5)
     # per token: wq 4*2*2 + wk,wv 2*4*1*2 + wo 2*2*4 + mlp 3*4*8 = 144 MACs
@@ -146,3 +150,69 @@ def test_counts_match_shapes_worked_by_hand():
     # rows only
     assert work.decode_bytes(untied, 3, 5) == 2 * (
         152 + 4 + 40 + 3 * 4 + 3 * 2 * 2 * 6)
+
+
+# sha256 (first 16 hex digits) of each served leaf's bf16 bytes at SMALL
+# and SEED, as the draw gave them before the dense family had a module of
+# its own
+PARENT_LEAVES = {
+    "granite-3-2b": {
+        "['blocks']['attn_norm']": "c436aa057b51dbc4",
+        "['blocks']['mlp_norm']": "215eb26145a3490e",
+        "['blocks']['wd']": "1125d9f0fa6c26b6",
+        "['blocks']['wg']": "a999ff1693842e12",
+        "['blocks']['wk']": "31ee41a02cb41e23",
+        "['blocks']['wo']": "7a4aa03036783781",
+        "['blocks']['wq']": "0fb7f18009d2a1a1",
+        "['blocks']['wu']": "289a3ba507f51941",
+        "['blocks']['wv']": "f666812ffea4c444",
+        "['embed']": "0541d556d2c9057a",
+        "['final_norm']": "c335778d95919f31",
+    },
+    "qwen2-7b": {
+        "['blocks']['attn_norm']": "304a9a9bc1a8f1a1",
+        "['blocks']['bk']": "ad807ea4e63e1b8a",
+        "['blocks']['bq']": "be97c1183eb0817a",
+        "['blocks']['bv']": "445ae23ab4499dff",
+        "['blocks']['mlp_norm']": "5dea6268b9902c31",
+        "['blocks']['wd']": "fc65d07a42044924",
+        "['blocks']['wg']": "fab6b73cee84d2bd",
+        "['blocks']['wk']": "0de03af1e3dbac35",
+        "['blocks']['wo']": "9a06283d00092886",
+        "['blocks']['wq']": "fd22b5705e86d4bb",
+        "['blocks']['wu']": "a7327f834f98a9e6",
+        "['blocks']['wv']": "5685992d0fb2924a",
+        "['embed']": "6598f431b56393fd",
+        "['final_norm']": "c335778d95919f31",
+        "['lm_head']": "eccd717bb51cc7d8",
+    },
+}
+
+
+@pytest.mark.parametrize("repo_arch", sorted(SMALL))
+def test_the_draw_is_the_one_before_families(repo_arch):
+    """The same seed gives a bit-identical served tree."""
+    m, _ = _small(repo_arch)
+    flat = jax.tree_util.tree_flatten_with_path(
+        dense.served_params(m, W.seed_words(SEED)))[0]
+    got = {jax.tree_util.keystr(k): hashlib.sha256(
+        np.asarray(v).tobytes()).hexdigest()[:16] for k, v in flat}
+    assert got == PARENT_LEAVES[repo_arch]
+
+
+def test_the_counts_at_ta_granites_shapes_are_the_ones_before_families():
+    """Prefill of 4 x 128 tokens and the decode steps from 128 to 135
+    cached positions, at the published shapes of ``ta-granite``: the
+    operations and bytes that ``prefill_mfu``, ``decode_hbm_roofline`` and
+    ``step_mfu`` divide by."""
+    cfg = json.loads((Path(__file__).resolve().parents[2] / "benchmarks"
+                      / "chip" / "configs" / "ta-granite.json").read_text())
+    m = dense.DenseDims.from_keys(cfg["served_as"], cfg)
+    assert work.prefill_flops(m, 4, 128) == 2502707691520.0
+    flops = [20436008960.0, 20437319680.0, 20438630400.0, 20439941120.0,
+             20441251840.0, 20442562560.0, 20443873280.0, 20445184000.0]
+    nbytes = [5109334016.0, 5109661696.0, 5109989376.0, 5110317056.0,
+              5110644736.0, 5110972416.0, 5111300096.0, 5111627776.0]
+    for i, cache_len in enumerate(range(128, 136)):
+        assert work.decode_flops(m, 4, cache_len) == flops[i]
+        assert work.decode_bytes(m, 4, cache_len) == nbytes[i]
