@@ -13,7 +13,8 @@ One process runs every phase in order, and any failure exits non-zero:
    must come back ``ok`` within its deadline, ``/metrics`` must count
    every completion, and nothing may compile while serving.
 3. **engine**: the engine's prefill-then-decode logits for one prompt
-   are compared with ``Model.forward`` over the same tokens.
+   are compared with ``Model.forward`` over the same tokens, and the
+   tokens its served programs pick must have the best logits.
 4. **kernels**: the flash and decode attention Pallas kernels, compiled
    by Mosaic at granite-3-2b widths, are compared with ``kernels/ref.py``.
 
@@ -191,24 +192,31 @@ def serve_phase(backend: EngineBackend,
 def consistency_phase(engine: Any, prompt_len: int,
                       seed: int = 0) -> Dict[str, float]:
     """Prefill logits at the last prompt position, and the next decode
-    step's, against one ``Model.forward`` over the same token sequence."""
+    step's, against one ``Model.forward`` over the same token sequence;
+    and the tokens the served programs (``Engine.picking``) pick there,
+    whose logits must be the best ones."""
     model, params = engine.model, engine.params
     S = prompt_len
     tokens = np.random.default_rng(seed).integers(
         0, model.arch.vocab_size, size=(1, S + 1), dtype=np.int32)
+    cache, tp = engine.picking("prefill")(params, tokens[:, :S])
+    _, td = engine.picking("decode")(params, cache, np.int32(S),
+                                     tokens[:, S:])
     lp, cache = engine.prefill(params, jnp.asarray(tokens[:, :S]))
     ld, _ = engine.decode(params, cache, jnp.int32(S),
                           jnp.asarray(tokens[:, S:]))
     ref = np.asarray(jax.jit(model.forward)(params, jnp.asarray(tokens)),
                      np.float32)
+    lp, ld = (np.asarray(x, np.float32)[0, 0] for x in (lp, ld))
     out = {
         "scale": float(np.abs(ref).max()),
-        "prefill": float(np.abs(np.asarray(lp, np.float32)[0, 0]
-                                - ref[0, S - 1]).max()),
-        "decode": float(np.abs(np.asarray(ld, np.float32)[0, 0]
-                               - ref[0, S]).max()),
+        "prefill": float(np.abs(lp - ref[0, S - 1]).max()),
+        "decode": float(np.abs(ld - ref[0, S]).max()),
+        "pick": max(float(lp.max() - lp[int(np.asarray(tp)[0, 0])]),
+                    float(ld.max() - ld[int(np.asarray(td)[0, 0])])),
     }
-    if max(out["prefill"], out["decode"]) > LOGIT_RTOL * out["scale"]:
+    if max(out["prefill"], out["decode"],
+           out["pick"]) > LOGIT_RTOL * out["scale"]:
         raise SmokeFailure(f"engine vs forward: {out}, "
                            f"tolerance {LOGIT_RTOL} x scale")
     return out
@@ -286,6 +294,7 @@ def main() -> int:
     con = consistency_phase(eng, PROMPT_LEN)
     print(f"engine vs forward (bf16): max|dlogit| prefill="
           f"{con['prefill']:.5f} decode={con['decode']:.5f} "
+          f"pick={con['pick']:.5f} "
           f"logit scale={con['scale']:.4f} "
           f"tolerance={LOGIT_RTOL * con['scale']:.5f}", flush=True)
 

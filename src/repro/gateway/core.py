@@ -595,6 +595,7 @@ class AsyncGateway:
             "batch_wait (a task queue holds a request), empty",
             ("state",))
         phases = getattr(self.backend, "engine_phases", None)
+        steps = getattr(self.backend, "engine_steps", None)
         compiles = getattr(self.backend, "compile_totals", None)
         if phases is not None:
             gens = registry.counter(
@@ -603,8 +604,18 @@ class AsyncGateway:
             secs = registry.counter(
                 "jigsaw_engine_seconds_total",
                 "Engine.generate wall seconds by phase: host (dispatch of "
-                "prefill, decode and argmax), sync (blocked pulling each "
-                "token to the host)", ("arch", "phase"))
+                "the prefill and decode programs, the greedy pick inside "
+                "them), sync (blocked pulling each token to the host while "
+                "the next step runs)", ("arch", "phase"))
+        if steps is not None:
+            ahead = registry.counter(
+                "jigsaw_engine_steps_ahead_total",
+                "Decode steps launched before the token they follow was "
+                "pulled to the host", ("arch",))
+            past = registry.counter(
+                "jigsaw_engine_steps_past_eos_total",
+                "Decode steps launched after every row of the batch had "
+                "ended, their tokens thrown away", ("arch",))
         if compiles is not None:
             n_c = registry.counter(
                 "jigsaw_compiles_total",
@@ -622,6 +633,10 @@ class AsyncGateway:
                     gens.set_total(g, arch)
                     secs.set_total(host, arch, "host")
                     secs.set_total(sync, arch, "sync")
+            if steps is not None:
+                for arch, (a, p) in steps().items():
+                    ahead.set_total(a, arch)
+                    past.set_total(p, arch)
             if compiles is not None:
                 n, seconds = compiles()
                 n_c.set_total(n)

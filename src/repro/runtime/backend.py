@@ -111,8 +111,9 @@ class EngineBackend:
     Service time is the measured wall-clock of the batched greedy decode,
     scaled by ``time_scale`` (sim-seconds per wall-second); a batch over
     ``max_batch`` runs as several launches, and their times add up.
-    ``engine_phases`` and ``compile_totals`` hand the engines' running
-    totals to whoever publishes them (the gateway, at scrape time).
+    ``engine_phases``, ``engine_steps`` and ``compile_totals`` hand the
+    engines' running totals to whoever publishes them (the gateway, at
+    scrape time).
 
     ``pool_time_scale`` maps a ClusterSpec pool name to ITS scale so a
     heterogeneous CPU parity run reflects relative device speeds (e.g.
@@ -198,6 +199,14 @@ class EngineBackend:
         """Per served arch: its engine's generate calls, and their host
         and sync seconds (``Engine.host_s`` / ``Engine.sync_s``)."""
         return {a: (e.generates, e.host_s, e.sync_s)
+                for a, e in self._engines.items()}
+
+    def engine_steps(self) -> Dict[str, Tuple[int, int]]:
+        """Per served arch: its engine's decode steps launched before the
+        token they follow was pulled, and those launched past every row's
+        end and thrown away (``Engine.steps_ahead`` /
+        ``Engine.steps_past_eos``)."""
+        return {a: (e.steps_ahead, e.steps_past_eos)
                 for a, e in self._engines.items()}
 
     def compile_totals(self) -> Tuple[int, float]:

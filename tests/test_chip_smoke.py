@@ -9,6 +9,7 @@ import time
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -43,6 +44,7 @@ def test_consistency_phase_reduced(reduced_backend):
     out = chip_smoke.consistency_phase(eng, reduced_backend.prompt_len)
     # float32 at reduced widths: the paths agree far inside the bf16 bound
     assert max(out["prefill"], out["decode"]) < 1e-4 * out["scale"]
+    assert out["pick"] == 0.0
 
 
 def test_consistency_phase_catches_a_wrong_cache(reduced_backend):
@@ -52,7 +54,7 @@ def test_consistency_phase_catches_a_wrong_cache(reduced_backend):
     class OtherCache:
         def __init__(self):
             self.model, self.params = eng.model, eng.params
-            self.decode = eng.decode
+            self.decode, self.picking = eng.decode, eng.picking
 
         def prefill(self, params, tokens):
             logits, _ = eng.prefill(params, tokens)
@@ -61,6 +63,27 @@ def test_consistency_phase_catches_a_wrong_cache(reduced_backend):
 
     with pytest.raises(chip_smoke.SmokeFailure):
         chip_smoke.consistency_phase(OtherCache(),
+                                     reduced_backend.prompt_len)
+
+
+def test_consistency_phase_catches_a_wrong_pick(reduced_backend):
+    """A served program that picks another token than the best fails the
+    check."""
+    eng = reduced_backend.engine_for(chip_smoke.ARCH)
+
+    class WorstPick:
+        def __init__(self):
+            self.model, self.params = eng.model, eng.params
+            self.prefill, self.decode = eng.prefill, eng.decode
+
+        def picking(self, name):
+            def worst(*args):
+                logits, cache = getattr(eng, name)(*args)
+                return cache, jnp.argmin(logits[:, -1], -1)[:, None]
+            return worst
+
+    with pytest.raises(chip_smoke.SmokeFailure):
+        chip_smoke.consistency_phase(WorstPick(),
                                      reduced_backend.prompt_len)
 
 

@@ -59,10 +59,14 @@ class Blocking:
 
 
 class WithEngines(Blocking):
-    """... that also keeps engine phases and compile counts."""
+    """... that also keeps engine phases, step counts and compile
+    counts."""
 
     def engine_phases(self):
         return {"arch-a": (self.calls, 0.25 * self.calls, 0.5)}
+
+    def engine_steps(self):
+        return {"arch-a": (7 * self.calls, 1)}
 
     def compile_totals(self):
         return 3, 1.5
@@ -210,6 +214,10 @@ def test_metrics_expose_the_backends_engine_phases_and_compiles(planned):
     assert sec[(("arch", "arch-a"), ("phase", "host"))] == \
         pytest.approx(0.25 * backend.calls)
     assert sec[(("arch", "arch-a"), ("phase", "sync"))] == 0.5
+    assert scrape["jigsaw_engine_steps_ahead_total"] == {
+        (("arch", "arch-a"),): 7 * backend.calls}
+    assert scrape["jigsaw_engine_steps_past_eos_total"] == {
+        (("arch", "arch-a"),): 1}
     assert scrape["jigsaw_compiles_total"] == {(): 3}
     assert scrape["jigsaw_compile_seconds_total"] == {(): 1.5}
 
@@ -219,6 +227,7 @@ def test_a_backend_without_engines_publishes_the_loop_alone(planned):
                              n_roots=2)
     assert "jigsaw_gateway_loop_seconds_total" in scrape
     assert "jigsaw_engine_seconds_total" not in scrape
+    assert "jigsaw_engine_steps_ahead_total" not in scrape
     assert "jigsaw_compiles_total" not in scrape
 
 

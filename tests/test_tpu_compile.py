@@ -123,14 +123,20 @@ def _engine_programs(sharding):
     return eng, params, prompt, place(cache), tok
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("program", ["prefill", "decode", "picked_prefill",
+                                     "picked_decode"])
 def test_full_width_granite_fits_one_chip(one_chip, program):
+    """The engine's programs, and the ones ``generate`` serves with the
+    greedy pick folded in (``Engine.picking``)."""
     eng, params, prompt, cache, tok = _engine_programs(one_chip)
-    if program == "prefill":
-        lowered = eng.prefill.lower(params, prompt)
+    name = program.rsplit("_", 1)[-1]
+    fn = (eng.picking(name) if program.startswith("picked_")
+          else getattr(eng, name))
+    if name == "prefill":
+        lowered = fn.lower(params, prompt)
     else:
-        lowered = eng.decode.lower(params, cache,
-                                   _spec((), jnp.int32, one_chip), tok)
+        lowered = fn.lower(params, cache, _spec((), jnp.int32, one_chip),
+                           tok)
     mem = lowered.compile().memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
